@@ -1,4 +1,4 @@
-"""qwen3-1.7b [hf:Qwen/Qwen3-8B family] — dense, qk_norm, GQA kv=8."""
+"""qwen3-1.7b [hf:Qwen/Qwen3-1.7B config.json] — dense, qk_norm, GQA kv=8."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -18,7 +18,7 @@ CONFIG = ModelConfig(
     qk_norm=True,
     tie_embeddings=True,
     max_seq_len=32768,
-    source="hf:Qwen/Qwen3-8B",
+    source="hf:Qwen/Qwen3-1.7B",
 )
 
 

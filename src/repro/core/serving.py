@@ -186,6 +186,13 @@ class ServeReport:
         return sum(1 for h in self.handles if h.done)
 
     @property
+    def unfinished(self) -> List[int]:
+        """rids admitted but not finished. Admission rejections are
+        terminal answers, not unfinished work."""
+        return sorted(h.rid for h in self.handles
+                      if not h.done and not h.rejected)
+
+    @property
     def attainment(self) -> float:
         """Fraction of *all* submitted requests finishing inside their
         (tier-scaled) SLO — unfinished requests count as misses."""

@@ -68,15 +68,6 @@ def collective_bytes(hlo_text: str) -> dict:
     return out
 
 
-def _cost_dict(compiled) -> dict:
-    """cost_analysis() returns a dict on current jax but a one-element list
-    of dicts on older jaxlib (e.g. 0.4.36) — normalise to a dict."""
-    c = compiled.cost_analysis()
-    if isinstance(c, (list, tuple)):
-        c = c[0] if c else {}
-    return c
-
-
 def _measure(cfg, shape, mesh, opts: frozenset = frozenset()) -> dict:
     """lower+compile one config; return per-device cost terms."""
     fn, args = build_dryrun(cfg, shape, mesh, opts)
@@ -85,7 +76,7 @@ def _measure(cfg, shape, mesh, opts: frozenset = frozenset()) -> dict:
     t1 = time.time()
     compiled = lowered.compile()
     t2 = time.time()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     return {
         "lower_s": round(t1 - t0, 1),

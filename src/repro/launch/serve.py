@@ -1,11 +1,12 @@
 """Serving launcher — one ServingSystem front-end over both backends: the
-real-compute Arrow cluster on CPU with a reduced model, or the cluster-scale
+real-compute Arrow cluster (the published-width model on a TPU; ``--smoke``
+selects the reduced float32 model for the CPU), or the cluster-scale
 simulator for full configs. Requests, traces and reporting share one path
 (DESIGN.md §1), so sim-vs-engine runs are directly comparable.
 
   PYTHONPATH=src python -m repro.launch.serve --mode engine --requests 16
-  PYTHONPATH=src python -m repro.launch.serve --mode engine --trace azure_code \
-      --rate 2 --duration 10 --policy colocated
+  PYTHONPATH=src python -m repro.launch.serve --mode engine --smoke \
+      --trace azure_code --rate 2 --duration 10 --policy colocated
   PYTHONPATH=src python -m repro.launch.serve --mode sim --arch gemma-2b \
       --trace azure_code --rate 8
   PYTHONPATH=src python -m repro.launch.serve --mode sim --trace spike \
@@ -17,6 +18,8 @@ and exit (docs/OPERATOR.md).
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
 from typing import List, Optional
 
 import numpy as np
@@ -28,6 +31,21 @@ from repro.core.policies import POLICIES
 from repro.core.request import Request, SamplingParams
 from repro.core.serving import ServeReport, ServingSystem, replay_trace
 from repro.core.slo import SLO
+
+#: JAX's persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: a fixed path inside the checkout (the path is part of the cache
+#: key, so a directory that moves never hits).
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is left
+    alone. Call from an entry point, never at import."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return jax.config.jax_compilation_cache_dir
 
 
 def synth_requests(n: int, gap: float, vocab: int, seed: int = 0
@@ -105,25 +123,42 @@ def list_policies() -> None:
           "detection)")
 
 
-def run_engine(args) -> ServeReport:
+def slot_capacity(trace: List[Request], cfg) -> int:
+    """KV slot capacity for serving ``trace``: its largest prompt plus
+    output, rounded up to the 128-token attention page, capped at the
+    model's ``max_seq_len``."""
+    need = max((r.input_len + r.output_len for r in trace), default=1)
+    return min(-(-need // 128) * 128, cfg.max_seq_len)
+
+
+def serve_engine(cfg, trace: List[Request], *, instances: int = 2,
+                 capacity: Optional[int] = None,
+                 slo: SLO = SLO(5.0, 2.0), policy: str = "arrow",
+                 seed: int = 0, params=None, tier: str = "standard",
+                 timeout: Optional[float] = 300.0,
+                 label: str = "serve-engine", **features):
+    """Serve ``trace`` on a real-compute Arrow cluster of ``instances``
+    with 8 slots each (half of them, at least one, start in the prefill
+    pool) and report.
+    ``capacity`` defaults to :func:`slot_capacity`; ``features`` are the
+    cluster's optional mechanisms (speculation, prefix cache, faults, ...).
+    Returns ``(cluster, report)``."""
     from repro.engine import ArrowEngineCluster
-    cfg = get_smoke_config(args.arch).replace(attn_impl=args.attn_impl)
     if cfg.family not in ("dense", "ssm", "hybrid"):
         raise SystemExit("--mode engine supports dense/ssm/hybrid archs; use "
                          "--mode sim for the rest (DESIGN.md §2, §13)")
-    cluster = ArrowEngineCluster(cfg, n_instances=args.instances,
-                                 n_prefill=max(args.instances // 2, 1),
-                                 n_slots=8, capacity=256,
-                                 slo=SLO(args.ttft, args.tpot),
-                                 policy=args.policy, seed=args.seed,
-                                 speculate=args.speculate,
-                                 autoscaler_cfg=autoscaler_cfg(args),
-                                 prefix_cache=args.prefix_cache == "on",
-                                 fault_plan=fault_plan(args),
-                                 tenants=tenant_registry(args),
-                                 admission=args.admission == "on",
-                                 deflection=deflection_cfg(args),
-                                 health=health_cfg(args))
+    cluster = ArrowEngineCluster(
+        cfg, n_instances=instances, n_prefill=max(instances // 2, 1),
+        capacity=capacity or slot_capacity(trace, cfg),
+        slo=slo, policy=policy, seed=seed, params=params, **features)
+    report = run_and_report(cluster, trace, tier=tier, timeout=timeout,
+                            label=label)
+    return cluster, report
+
+
+def run_engine(args) -> ServeReport:
+    get = get_smoke_config if args.smoke else get_config
+    cfg = get(args.arch).replace(attn_impl=args.attn_impl)
     if args.trace:
         from repro.traces import load_trace
         trace = load_trace(args.trace, rate_scale=args.rate, seed=0,
@@ -131,9 +166,15 @@ def run_engine(args) -> ServeReport:
     else:
         trace = synth_requests(args.requests, args.gap, cfg.vocab_size)
     trace = apply_sampling(trace, args)
-    return run_and_report(cluster, trace, tier=args.tier,
-                          timeout=args.timeout,
-                          label=f"serve-engine {args.policy}")
+    _, report = serve_engine(
+        cfg, trace, instances=args.instances, slo=SLO(args.ttft, args.tpot),
+        policy=args.policy, seed=args.seed, tier=args.tier,
+        timeout=args.timeout, label=f"serve-engine {args.policy}",
+        speculate=args.speculate, autoscaler_cfg=autoscaler_cfg(args),
+        prefix_cache=args.prefix_cache == "on", fault_plan=fault_plan(args),
+        tenants=tenant_registry(args), admission=args.admission == "on",
+        deflection=deflection_cfg(args), health=health_cfg(args))
+    return report
 
 
 def run_sim(args) -> ServeReport:
@@ -267,14 +308,22 @@ def build_parser() -> argparse.ArgumentParser:
                          "lose their KV; the runtime recovers the lost "
                          "requests (and an elastic policy replaces the "
                          "instance)")
+    ap.add_argument("--smoke", action="store_true",
+                    help="engine mode: serve the reduced float32 config "
+                         "(2 layers, d_model 128) that runs on the CPU, in "
+                         "place of the published-width bf16 config a TPU "
+                         "serves; sim mode ignores this flag")
     ap.add_argument("--attn-impl", choices=("reference", "pallas"),
                     default="reference",
                     help="engine-mode attention implementation (DESIGN.md "
                          "§9): 'reference' = pure-jnp sdpa; 'pallas' = the "
-                         "flash_prefill/paged_attention kernels (interpret "
-                         "mode on CPU — validates the kernel contract, not "
-                         "CPU speed). Greedy streams are identical either "
-                         "way; sim mode ignores this flag")
+                         "flash_prefill/paged_attention kernels, compiled "
+                         "by Mosaic on a TPU and run in interpret mode on "
+                         "the CPU (which checks the kernel contract, not "
+                         "speed, and misses Mosaic's refusals: "
+                         "tests/test_tpu_compile.py guards those). Greedy "
+                         "streams are identical either way; sim mode "
+                         "ignores this flag")
     ap.add_argument("--prefix-cache", choices=("on", "off"), default="off",
                     help="prefix-aware KV reuse (DESIGN.md §7): retain "
                          "finished contexts and prefill only the uncached "
@@ -369,12 +418,19 @@ def main(argv=None) -> None:
         return list_traces()
     if args.list_policies:
         return list_policies()
+    enable_compile_cache()
     if args.mode == "engine":
-        run_engine(args)
+        report = run_engine(args)
     else:
         if args.trace is None:
             args.trace = "azure_code"
-        run_sim(args)
+        report = run_sim(args)
+    # a drain timeout or a lost request fails the run; admission
+    # rejections are answers, not failures
+    if report.unfinished:
+        raise SystemExit(f"[serve-{args.mode}] {len(report.unfinished)} "
+                         f"admitted request(s) unfinished: rids "
+                         f"{report.unfinished}")
 
 
 if __name__ == "__main__":

@@ -106,8 +106,6 @@ with mesh:
     fn, args = build_dryrun(cfg, shape, mesh)
     compiled = fn.lower(*args).compile()
     c = compiled.cost_analysis()
-    if isinstance(c, (list, tuple)):      # older jaxlib returns [dict]
-        c = c[0] if c else {{}}
     assert c.get("flops", 0) > 0
 print("OK", c.get("flops"))
 """
@@ -130,7 +128,8 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.models import build_model
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_smoke_config("qwen3-1.7b").replace(dtype="float32")
 model = build_model(cfg)
 params = model.init(jax.random.PRNGKey(0))

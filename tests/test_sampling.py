@@ -96,7 +96,9 @@ def test_sampled_streams_match_golden_pin(setup):
     """Seeded sampled streams are part of the replay contract too: the
     exact ``fold_in(fold_in(key(seed), rid), position)`` derivation and the
     Gumbel-max nucleus rule are pinned, so any change to key order,
-    position bookkeeping or the keep-mass rule shows up as a diff here."""
+    position bookkeeping or the keep-mass rule shows up as a diff here.
+    The Gumbel noise comes from JAX's PRNG bits, so the file records the
+    JAX version this section was pinned under (``sampled_jax_version``)."""
     cfg, model, params = setup
     golden = json.loads(GOLDEN.read_text())["sampled"]
     sp = SamplingParams(temperature=0.9, top_p=0.9, seed=77)
