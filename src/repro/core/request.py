@@ -84,8 +84,14 @@ class Request:
     finish_time: Optional[float] = None
     token_times: list = field(default_factory=list)  # absolute times of o_2..o_m
 
+    # queue stamps, on the cluster clock: each is set the first time and a
+    # crash-recovery re-prefill keeps it. With ``arrival`` and
+    # ``first_token_time`` they give the wait in each pool.
+    prefill_start_time: Optional[float] = None    # first chunk dispatched
+    migrate_start_time: Optional[float] = None    # state transfer begun
+    decode_start_time: Optional[float] = None     # joined a decode batch
+
     # progress
-    prefill_done_tokens: int = 0     # chunked-prefill progress
     decoded_tokens: int = 0          # output tokens produced so far (incl. o_1)
 
     @property

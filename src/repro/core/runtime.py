@@ -604,6 +604,8 @@ class RuntimeCore(ServingSystem):
         remaining = req.output_len - len(handle.tokens)
         if target == iid:
             req.state = RequestState.DECODING
+            if req.decode_start_time is None:
+                req.decode_start_time = now
             self.local_of(iid).start_local_decode(
                 req.rid, req.input_len, remaining)
             return DecodePlacement.LOCAL, iid
@@ -646,6 +648,9 @@ class RuntimeCore(ServingSystem):
             # _transfers keys the in-flight set a crash must abort (§8).
             self._kv_inbound[iid] += 1
             self._transfers[rid] = (self._kv_source(rid), iid, kv)
+            req = self.handles[rid].req
+            if req.migrate_start_time is None:
+                req.migrate_start_time = self.clock.now()
             if not self._begin_transfer(rid, iid, kv, rem):
                 self._kv_inbound[iid] -= 1
                 self._transfers.pop(rid, None)
@@ -669,8 +674,7 @@ class RuntimeCore(ServingSystem):
     def complete_migration(self, rid: int, dst: int, kv: int, rem: int,
                            now: float) -> None:
         """KV landed on ``dst``: release it at the source, join the decode
-        set. (``now`` kept for symmetry/overrides; completion itself is not a
-        scheduling decision.)"""
+        set; ``now`` stamps the request's decode start."""
         req = self.handles[rid].req
         src = self._kv_source(rid)
         self._migrating_from.pop(rid, None)
@@ -686,6 +690,8 @@ class RuntimeCore(ServingSystem):
         self.local_of(dst).admit_migrated(rid, kv, rem)
         req.state = RequestState.DECODING
         req.decode_instance = dst
+        if req.decode_start_time is None:
+            req.decode_start_time = now
         self._decode_started(dst)
 
     # ----------------------------------- instance lifecycle (DESIGN.md §6)
@@ -1038,7 +1044,6 @@ class RuntimeCore(ServingSystem):
         req.prefill_instance = None
         req.decode_instance = None
         req.cached_len = 0
-        req.prefill_done_tokens = 0
         self._migrating_from.pop(rid, None)
         self._xfer_attempts.pop(rid, None)
         src = self._prefix_src.pop(rid, None)

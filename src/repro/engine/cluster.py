@@ -28,6 +28,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core import (Request, RequestState, SLO, SchedulerConfig,
@@ -141,54 +142,61 @@ class ArrowEngineCluster(RuntimeCore):
         # already emitted, the destination so its donated slabs aren't
         # mid-flight
         src = self._kv_source(rid)
-        self._finalize_now(src)
-        self._finalize_now(dst)
-        samp = self.instances[src].kv.samp_of.get(rid)
-        payload, L, last, gen = self.instances[src].export_state(rid)
-        # end-to-end integrity (§14): checksum at export, verify at import.
-        # The source slot is retained until complete_migration acknowledges,
-        # so every retry re-sends pristine state.
-        checksum = state_checksum(payload)
-        while True:
-            nf = self.netslow_factor(self.clock.now())
-            if nf > 1.0:                  # degraded interconnect window (§14)
-                time.sleep(min((nf - 1.0) * 1e-3, 0.05))
-            wire = payload
-            if self.xfer_should_drop(self.clock.now()):
-                # a dropped attempt materializes as a corrupt wire image;
-                # corrupt a *copy* so the source arrays stay pristine
-                wire = [np.array(p, copy=True) for p in payload]
-                for p in wire:
-                    if p.size:
-                        p.view(np.uint8).reshape(-1)[0] ^= 0xFF
-                        break
-                self.health_stats["xfer_corrupt"] += 1
-            try:
-                ok = self.instances[dst].import_state(
-                    rid, wire, L, last, gen, sampling=samp, checksum=checksum)
-            except CorruptPayload:
-                attempt = self.note_xfer_drop(rid)
-                if attempt <= self.xfer_retry_budget():
-                    self.health_stats["xfer_retries"] += 1
-                    time.sleep(min(self.xfer_backoff(attempt), 0.05))
-                    continue
-                # retries exhausted: fall through to re-prefill recovery
-                # (§8); the transfer item is consumed, not requeued
-                self.fail_transfer(rid, dst, kv, self.clock.now())
-                return True
-            break
-        if not ok:
-            # no free slot: cached prefixes are reclaimable capacity (§7)
-            if not (self.prefix_mgr is not None
-                    and self.prefix_mgr.evict_one(dst) is not None
-                    and self.instances[dst].import_state(rid, payload, L, last,
-                                                         gen, sampling=samp)):
-                return False                    # genuinely full: retry later
-        # the wire cost is the payload's actual bytes — O(1) in context for
-        # constant-state families, tokens × per-token KV for dense (§13)
-        self._record_migration(rid, L, sum(int(p.nbytes) for p in payload))
-        self.complete_migration(rid, dst, kv, rem, self.clock.now())
-        return True
+        with TraceAnnotation("arrow.migrate", rid=rid, src=src,
+                             dst=dst) as span:
+            with TraceAnnotation("arrow.migrate.land"):
+                self._finalize_now(src)
+                self._finalize_now(dst)
+            samp = self.instances[src].kv.samp_of.get(rid)
+            payload, L, last, gen = self.instances[src].export_state(rid)
+            # the wire cost is the payload's actual bytes — O(1) in context
+            # for constant-state families, tokens × per-token KV for dense
+            # (§13)
+            nbytes = sum(int(p.nbytes) for p in payload)
+            span.set_metadata(bytes=nbytes)
+            # end-to-end integrity (§14): checksum at export, verify at
+            # import. The source slot is retained until complete_migration
+            # acknowledges, so every retry re-sends pristine state.
+            checksum = state_checksum(payload)
+            while True:
+                nf = self.netslow_factor(self.clock.now())
+                if nf > 1.0:              # degraded interconnect window (§14)
+                    time.sleep(min((nf - 1.0) * 1e-3, 0.05))
+                wire = payload
+                if self.xfer_should_drop(self.clock.now()):
+                    # a dropped attempt materializes as a corrupt wire
+                    # image; corrupt a *copy* so the source stays pristine
+                    wire = [np.array(p, copy=True) for p in payload]
+                    for p in wire:
+                        if p.size:
+                            p.view(np.uint8).reshape(-1)[0] ^= 0xFF
+                            break
+                    self.health_stats["xfer_corrupt"] += 1
+                try:
+                    ok = self.instances[dst].import_state(
+                        rid, wire, L, last, gen, sampling=samp,
+                        checksum=checksum)
+                except CorruptPayload:
+                    attempt = self.note_xfer_drop(rid)
+                    if attempt <= self.xfer_retry_budget():
+                        self.health_stats["xfer_retries"] += 1
+                        time.sleep(min(self.xfer_backoff(attempt), 0.05))
+                        continue
+                    # retries exhausted: fall through to re-prefill recovery
+                    # (§8); the transfer item is consumed, not requeued
+                    self.fail_transfer(rid, dst, kv, self.clock.now())
+                    return True
+                break
+            if not ok:
+                # no free slot: cached prefixes are reclaimable capacity (§7)
+                if not (self.prefix_mgr is not None
+                        and self.prefix_mgr.evict_one(dst) is not None
+                        and self.instances[dst].import_state(
+                            rid, payload, L, last, gen, sampling=samp)):
+                    return False                # genuinely full: retry later
+            self._record_migration(rid, L, nbytes)
+            self.complete_migration(rid, dst, kv, rem, self.clock.now())
+            return True
 
     def _release_source_kv(self, src: int, rid: int, kv: int) -> None:
         # free the slot *and* the LocalScheduler token accounting (the gate
@@ -415,51 +423,58 @@ class ArrowEngineCluster(RuntimeCore):
         is ready and nothing can be dispatched, the oldest inflight step is
         force-finalized so the pass always makes progress instead of
         spinning the host."""
-        t = self.clock.now()
-        if self.fault_injector is not None:    # polled firing (§8)
-            self.fault_injector.poll(t)
-        # arrivals due
-        while self._pending and self._pending[0][0] <= t:
-            _, rid = heapq.heappop(self._pending)
-            handle = self.handles[rid]
-            if self.dispatch_prefill(handle, t) is None:
-                continue       # deferred: re-enters _pending via _arrival_due
-            self._live[rid] = handle
-        # migrations (instant data move + admission gate); snapshot the id
-        # lists — elastic retirement may remove instances mid-pass
-        for dst in list(self.instances):
-            self.admit_migrations(dst)
-        # collect-ready: finalize any inflight step whose token arrays have
-        # landed; the rest keep computing
-        progressed = 0
-        for iid in list(self._inflight):
-            inst = self.instances.get(iid)
-            if inst is None:                  # died while inflight
-                self._inflight.pop(iid, None)
-                continue
-            if self._inflight[iid][0].ready():
-                ctx = self._inflight.pop(iid)
-                self._finalize_instance_step(iid, inst, ctx)
-                progressed += 1
-        # dispatch-all: every instance without an inflight step launches its
-        # next fused step and returns immediately
-        for iid, inst in list(self.instances.items()):
-            if iid in self._inflight or iid not in self.instances:
-                continue
-            ctx = self._dispatch_instance(iid, inst)
-            if ctx is not None:
-                self._inflight[iid] = ctx
-                progressed += 1
-        if not progressed and self._inflight:
-            # nothing landed, nothing to launch: block on the oldest
-            # inflight step rather than busy-spinning the host
-            self._finalize_now(next(iter(self._inflight)))
-        # monitor tick
-        now = self.clock.now()
-        if now - self._last_tick >= self.sched_cfg.monitor_interval:
-            self._last_tick = now
-            self.collect_stats(now)
-        return bool(self._live or self._pending or self._inflight)
+        with TraceAnnotation("arrow.pass", inflight=len(self._inflight)):
+            t = self.clock.now()
+            if self.fault_injector is not None:    # polled firing (§8)
+                self.fault_injector.poll(t)
+            with TraceAnnotation("arrow.arrivals") as span:
+                n = 0
+                while self._pending and self._pending[0][0] <= t:
+                    _, rid = heapq.heappop(self._pending)
+                    n += 1
+                    handle = self.handles[rid]
+                    if self.dispatch_prefill(handle, t) is None:
+                        continue   # deferred: re-enters _pending (_arrival_due)
+                    self._live[rid] = handle
+                span.set_metadata(n=n)
+            # migrations (instant data move + admission gate); snapshot the
+            # id lists — elastic retirement may remove instances mid-pass
+            for dst in list(self.instances):
+                self.admit_migrations(dst)
+            # collect-ready: finalize any inflight step whose token arrays
+            # have landed; the rest keep computing
+            progressed = 0
+            for iid in list(self._inflight):
+                inst = self.instances.get(iid)
+                if inst is None:                  # died while inflight
+                    self._inflight.pop(iid, None)
+                    continue
+                if self._inflight[iid][0].ready():
+                    ctx = self._inflight.pop(iid)
+                    self._finalize_instance_step(iid, inst, ctx)
+                    progressed += 1
+            # dispatch-all: every instance without an inflight step launches
+            # its next fused step and returns immediately
+            for iid, inst in list(self.instances.items()):
+                if iid in self._inflight or iid not in self.instances:
+                    continue
+                ctx = self._dispatch_instance(iid, inst)
+                if ctx is not None:
+                    self._inflight[iid] = ctx
+                    progressed += 1
+            if not progressed and self._inflight:
+                # nothing landed, nothing to launch: block on the oldest
+                # inflight step rather than busy-spinning the host
+                oldest = next(iter(self._inflight))
+                with TraceAnnotation("arrow.block", iid=oldest):
+                    self._finalize_now(oldest)
+            # monitor tick
+            now = self.clock.now()
+            if now - self._last_tick >= self.sched_cfg.monitor_interval:
+                self._last_tick = now
+                with TraceAnnotation("arrow.tick"):
+                    self.collect_stats(now)
+            return bool(self._live or self._pending or self._inflight)
 
     def run_until(self, t: float) -> None:
         while self.clock.now() < t:
@@ -499,10 +514,31 @@ class ArrowEngineCluster(RuntimeCore):
     def _dispatch_instance(self, iid: int, inst: EngineInstance):
         """Phase 1: admit the plan's chunks (slot allocation / cached-prefix
         seeding) and launch the instance's fused step without blocking."""
-        plan = inst.local.plan_iteration()
-        if plan.is_empty:
-            return None
-        t_start = self.clock.now()
+        with TraceAnnotation("arrow.dispatch", iid=iid) as span:
+            with TraceAnnotation("arrow.dispatch.plan"):
+                plan = inst.local.plan_iteration()
+                if plan.is_empty:
+                    return None
+                t_start = self.clock.now()
+                chunks = self._admit_chunks(iid, inst, plan, t_start)
+            pending = inst.dispatch_step(plan.decode_rids, chunks)
+            if pending is None:
+                return None
+            span.set_metadata(step=pending.step, rows=len(plan.decode_rids),
+                              slots=inst.kv.n_slots, chunks=len(chunks),
+                              chunk_tokens=sum(c.length for c in chunks),
+                              entry=pending.entry)
+            # t_disp closes this instance's own dispatch span; the finalize
+            # span is measured separately so an instance's iteration
+            # duration (the TPOT signal) and any injected slowdown never
+            # absorb the *other* instances' dispatch/finalize work done in
+            # between
+            return pending, chunks, t_start, self.clock.now()
+
+    def _admit_chunks(self, iid: int, inst: EngineInstance, plan,
+                      now: float) -> List[ChunkWork]:
+        """The plan's prefill chunks that get a slot, each with its prompt
+        tokens; a request's first chunk stamps its prefill start."""
         chunks = []
         # the legacy baseline is the *pre-fusion* path faithfully: it
         # processed at most one prefill chunk per cooperative pass
@@ -531,31 +567,41 @@ class ArrowEngineCluster(RuntimeCore):
                 # (recovery re-runs this path, so a recovered stream keeps
                 # its keys — DESIGN.md §12)
                 inst.set_sampling(rid, handle.req.sampling)
+            if handle.req.prefill_start_time is None:
+                handle.req.prefill_start_time = now
             prompt = self._prompts[rid]
             chunks.append(ChunkWork(rid, start, ln,
                                     prompt[start:start + ln],
                                     handle.req.input_len))
-        pending = inst.dispatch_step(plan.decode_rids, chunks)
-        if pending is None:
-            return None
-        # t_disp closes this instance's own dispatch span; the finalize span
-        # is measured separately so an instance's iteration duration (the
-        # TPOT signal) and any injected slowdown never absorb the *other*
-        # instances' dispatch/finalize work done in between
-        return pending, chunks, t_start, self.clock.now()
+        return chunks
 
     def _finalize_instance_step(self, iid: int, inst: EngineInstance,
                                 ctx) -> None:
         """Phase 2: the step's one blocking token fetch + host bookkeeping
         (stream emission, decode/prefill completion, Eq.(2) resync)."""
         pending, chunks, t_start, t_disp = ctx
-        slow = self.slow_factor(iid, t_start)    # injected lag (§8)
-        t_fin0 = self.clock.now()
-        done_tokens, chunk_tokens = inst.finalize_step(pending)
-        t_after = self.clock.now()
-        # this instance's own work: its dispatch span + its blocking fetch
-        # (the device compute overlapped the other instances' phases)
-        span = (t_disp - t_start) + (t_after - t_fin0)
+        with TraceAnnotation("arrow.fetch", iid=iid, step=pending.step):
+            slow = self.slow_factor(iid, t_start)    # injected lag (§8)
+            t_fin0 = self.clock.now()
+            done_tokens, chunk_tokens = inst.finalize_step(pending)
+            t_after = self.clock.now()
+            # this instance's own work: its dispatch span + its blocking
+            # fetch (the device compute overlapped the other instances')
+            span = (t_disp - t_start) + (t_after - t_fin0)
+            with TraceAnnotation("arrow.fetch.emit"):
+                self._emit_step(iid, inst, done_tokens, chunk_tokens, chunks,
+                                t_after, span)
+            if slow > 1.0:                           # lagging instance (§8)
+                time.sleep(min((slow - 1.0)
+                               * max(self.clock.now() - t_fin0
+                                     + (t_disp - t_start), 0.0), 0.25))
+
+    def _emit_step(self, iid: int, inst: EngineInstance, done_tokens,
+                   chunk_tokens, chunks: List[ChunkWork], t_after: float,
+                   span: float) -> None:
+        """A fetched step's host bookkeeping: stream its decode tokens and
+        finish requests, then complete its prefill chunks and place each
+        finished prompt's decode phase."""
         emitted = 0
         for rid, tok in done_tokens.items():
             handle = self._live.get(rid)
@@ -608,7 +654,3 @@ class ArrowEngineCluster(RuntimeCore):
                 if cw.rid not in inst.local.retained:
                     inst.drop(cw.rid)
                 self._live.pop(cw.rid, None)
-        if slow > 1.0:                           # lagging instance (§8)
-            time.sleep(min((slow - 1.0)
-                           * max(self.clock.now() - t_fin0 + (t_disp - t_start),
-                                 0.0), 0.25))

@@ -49,26 +49,31 @@ def _sample_one(cfg, logits, temp, top_p, seed, rid, pos):
     cross-instance (migration) bit-identity — fusion-level float noise
     only matters on exact score ties, which the Gumbel noise breaks.
     ``temp <= 0`` short-circuits to the pre-sampling argmax on the
-    original-dtype logits."""
-    V = cfg.vocab_size
-    row = logits[:V]
-    greedy = jnp.argmax(row).astype(jnp.int32)
-    rowf = row.astype(jnp.float32)
-    t = jnp.maximum(temp, 1e-6).astype(jnp.float32)
-    scaled = rowf / t
-    probs = jax.nn.softmax(scaled)
-    order = jnp.argsort(-probs)
-    sp = probs[order]
-    # nucleus rule: keep tokens whose *exclusive* prefix mass is < top_p —
-    # the top-1 token always survives (its exclusive mass is 0)
-    keep_sorted = (jnp.cumsum(sp) - sp) < jnp.maximum(top_p, 1e-6)
-    keep = jnp.zeros((row.shape[0],), bool).at[order].set(keep_sorted)
-    key = jax.random.fold_in(
-        jax.random.fold_in(jax.random.PRNGKey(seed), rid), pos)
-    g = jax.random.gumbel(key, (row.shape[0],), jnp.float32)
-    sampled = jnp.argmax(jnp.where(keep, scaled + g,
-                                   -jnp.inf)).astype(jnp.int32)
-    return jnp.where(temp <= 0.0, greedy, sampled)
+    original-dtype logits.
+
+    Every entry point reaches the sampler through here, so the ``sample``
+    scope names its ops (in the compiled program's op metadata) on every
+    path: decode rows through ``vmap``, chunk finals through ``scan``."""
+    with jax.named_scope("sample"):
+        V = cfg.vocab_size
+        row = logits[:V]
+        greedy = jnp.argmax(row).astype(jnp.int32)
+        rowf = row.astype(jnp.float32)
+        t = jnp.maximum(temp, 1e-6).astype(jnp.float32)
+        scaled = rowf / t
+        probs = jax.nn.softmax(scaled)
+        order = jnp.argsort(-probs)
+        sp = probs[order]
+        # nucleus rule: keep tokens whose *exclusive* prefix mass is < top_p
+        # — the top-1 token always survives (its exclusive mass is 0)
+        keep_sorted = (jnp.cumsum(sp) - sp) < jnp.maximum(top_p, 1e-6)
+        keep = jnp.zeros((row.shape[0],), bool).at[order].set(keep_sorted)
+        key = jax.random.fold_in(
+            jax.random.fold_in(jax.random.PRNGKey(seed), rid), pos)
+        g = jax.random.gumbel(key, (row.shape[0],), jnp.float32)
+        sampled = jnp.argmax(jnp.where(keep, scaled + g,
+                                       -jnp.inf)).astype(jnp.int32)
+        return jnp.where(temp <= 0.0, greedy, sampled)
 
 
 def _sample_rows(cfg, logits, temps, top_ps, seeds, rids, pos):

@@ -19,6 +19,7 @@ from __future__ import annotations
 import time
 import zlib
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -46,6 +47,7 @@ class CorruptPayload(RuntimeError):
         self.rid = rid
 
 
+@partial(jax.profiler.annotate_function, name="arrow.migrate.checksum")
 def state_checksum(payload) -> int:
     """CRC32 over the migration payload's raw bytes, chained across arrays.
     Computed at ``export_state`` time and verified at ``import_state`` time —
@@ -87,15 +89,27 @@ class PendingStep:
     ``[a, g_0..g_k]`` array. ``fetch`` is the step's blocking transfer;
     ``ready`` polls the device without blocking, which is what lets the
     cluster's async step collect finished instances while the rest keep
-    computing."""
+    computing. ``step`` is the instance's own step number, which links the
+    step's dispatch and fetch spans in a profiler trace."""
 
     def __init__(self, decode_rids: List[int],
                  groups: List[Tuple[List[ChunkWork], Any]],
-                 spec: Any = None, decode_in_group0: bool = True):
+                 spec: Any = None, decode_in_group0: bool = True,
+                 step: int = 0):
         self.decode_rids = decode_rids
         self.groups = groups
         self.spec = spec
         self.decode_in_group0 = decode_in_group0
+        self.step = step
+
+    @property
+    def entry(self) -> str:
+        """The fused-step entry point that carried the step's first group."""
+        if self.spec is not None:
+            return "spec_decode"
+        if not (self.decode_in_group0 and self.decode_rids):
+            return "chunks_only"
+        return "mixed_step" if self.groups[0][0] else "decode_only"
 
     def ready(self) -> bool:
         if self.spec is not None and not self.spec.is_ready():
@@ -122,10 +136,13 @@ class PendingStep:
 class _EagerStep:
     """Legacy-mode stand-in: results were computed synchronously."""
 
+    entry = "legacy"
+
     def __init__(self, decode_out: Dict[int, int],
-                 chunk_out: List[Tuple[int, Optional[int]]]):
+                 chunk_out: List[Tuple[int, Optional[int]]], step: int):
         self.decode_out = decode_out
         self.chunk_out = chunk_out
+        self.step = step
 
     def ready(self) -> bool:
         return True
@@ -185,6 +202,7 @@ class EngineInstance:
         # request bookkeeping
         self.last_token: Dict[int, int] = {}
         self.generated: Dict[int, List[int]] = {}
+        self.step_no = 0                  # steps dispatched so far
 
     # ------------------------------------------------------------- slots
     def alloc_slot(self, rid: int) -> int:
@@ -311,6 +329,7 @@ class EngineInstance:
         return decode_out
 
     # ------------------------------------------------------- fused step
+    @partial(jax.profiler.annotate_function, name="arrow.dispatch.launch")
     def dispatch_step(self, decode_rids: List[int],
                       chunks: Sequence[ChunkWork]):
         """Launch this instance's whole iteration — the decode batch plus
@@ -320,6 +339,7 @@ class EngineInstance:
         :meth:`finalize_step`."""
         if not decode_rids and not chunks:
             return None
+        self.step_no += 1
         if self.step_mode == "legacy":
             return self._legacy_step(decode_rids, chunks)
         dec_args = None
@@ -399,8 +419,10 @@ class EngineInstance:
             self.kv.swap(*out[1:])
             groups.append(([], out[0]))
         return PendingStep(list(decode_rids), groups, spec=spec_arr,
-                           decode_in_group0=dec_args is not None)
+                           decode_in_group0=dec_args is not None,
+                           step=self.step_no)
 
+    @partial(jax.profiler.annotate_function, name="arrow.fetch.wait")
     def finalize_step(self, pending) -> Tuple[Dict[int, Any],
                                               List[Tuple[int, Optional[int]]]]:
         """Fetch the step's stacked token array (the one blocking transfer)
@@ -466,7 +488,7 @@ class EngineInstance:
                      chunks: Sequence[ChunkWork]) -> _EagerStep:
         decode_out = self._legacy_decode(decode_rids) if decode_rids else {}
         chunk_out = [(cw.rid, self._legacy_chunk(cw)) for cw in chunks]
-        return _EagerStep(decode_out, chunk_out)
+        return _EagerStep(decode_out, chunk_out, self.step_no)
 
     def _legacy_decode(self, rids: List[int]) -> Dict[int, int]:
         """Pre-fusion decode, kept verbatim as the bench baseline: the
@@ -534,6 +556,7 @@ class EngineInstance:
         return None
 
     # --------------------------------------------------------- transfer
+    @partial(jax.profiler.annotate_function, name="arrow.migrate.export")
     def export_state(self, rid: int):
         """Family-agnostic migration export: (payload host arrays, context
         length, last token, generated tokens). ``sum(p.nbytes)`` over the
@@ -542,6 +565,7 @@ class EngineInstance:
         payload, L = self.kv.extract_state(rid)
         return payload, L, self.last_token[rid], self.generated[rid]
 
+    @partial(jax.profiler.annotate_function, name="arrow.migrate.import")
     def import_state(self, rid: int, payload, L: int, last_token: int,
                      generated: List[int], sampling=None,
                      checksum: Optional[int] = None) -> bool:

@@ -411,7 +411,8 @@ class Simulator(RuntimeCore):
             if rid not in loc.prefill_queue:
                 continue
             req = self.requests[rid]
-            req.prefill_done_tokens = start + ln
+            if req.prefill_start_time is None:
+                req.prefill_start_time = now - dur    # the iteration's start
             if loc.complete_prefill_chunk(rid, ln):
                 self._on_prefill_complete(iid, rid)
         self._busy[iid] = False
