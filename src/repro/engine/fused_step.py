@@ -51,6 +51,14 @@ def _sample_one(cfg, logits, temp, top_p, seed, rid, pos):
     ``temp <= 0`` short-circuits to the pre-sampling argmax on the
     original-dtype logits.
 
+    The nucleus mask is built in sorted order and moved back by a second
+    sort: one stable sort of ``(-probs, iota)`` gives both the descending
+    probabilities and ``order`` (the tie order of ``jnp.argsort``, with no
+    gather), and since ``order`` is a permutation, sorting
+    ``(order, keep_sorted)`` by ``order`` puts the mask in vocabulary
+    order, bit for bit the mask a scatter would build. On a TPU a gather
+    and a scatter over the whole vocabulary cost several times the sorts.
+
     Every entry point reaches the sampler through here, so the ``sample``
     scope names its ops (in the compiled program's op metadata) on every
     path: decode rows through ``vmap``, chunk finals through ``scan``."""
@@ -62,12 +70,13 @@ def _sample_one(cfg, logits, temp, top_p, seed, rid, pos):
         t = jnp.maximum(temp, 1e-6).astype(jnp.float32)
         scaled = rowf / t
         probs = jax.nn.softmax(scaled)
-        order = jnp.argsort(-probs)
-        sp = probs[order]
+        iota = lax.iota(jnp.int32, row.shape[0])
+        neg, order = lax.sort((-probs, iota), num_keys=1, is_stable=True)
+        sp = -neg
         # nucleus rule: keep tokens whose *exclusive* prefix mass is < top_p
         # — the top-1 token always survives (its exclusive mass is 0)
         keep_sorted = (jnp.cumsum(sp) - sp) < jnp.maximum(top_p, 1e-6)
-        keep = jnp.zeros((row.shape[0],), bool).at[order].set(keep_sorted)
+        _, keep = lax.sort((order, keep_sorted), num_keys=1)
         key = jax.random.fold_in(
             jax.random.fold_in(jax.random.PRNGKey(seed), rid), pos)
         g = jax.random.gumbel(key, (row.shape[0],), jnp.float32)

@@ -12,10 +12,13 @@ against golden streams recorded at PR 8 so greedy serving can never drift.
 Everything here asserts token *ids* (bit-identity), never timings, so a
 loaded CI machine can only time out, not produce a wrong pass.
 """
+import dataclasses
 import json
+from functools import partial
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from invariants import check_invariants
@@ -24,6 +27,7 @@ from repro.configs import get_smoke_config
 from repro.core import Request, SLO, SamplingParams
 from repro.core.faults import FaultPlan
 from repro.engine import ArrowEngineCluster, EngineInstance
+from repro.engine import fused_step as fs
 from repro.models import build_model
 
 DRAIN_TIMEOUT = 300.0
@@ -275,6 +279,118 @@ def test_speculative_greedy_matches_golden_pin(setup):
             inst.finalize_step(pend)
         assert inst.generated[rid + 200][:10] == golden[str(rid)]
         inst.drop(rid + 200)
+
+
+# ------------------------------------------- nucleus mask by two sorts
+
+def _gather_scatter_sample_one(cfg, logits, temp, top_p, seed, rid, pos):
+    """The sampler as it stood before the nucleus mask moved to two sorts:
+    ``argsort``, a gather of the sorted probabilities and a scatter of the
+    mask back to vocabulary order. Kept as the reference the two-sort
+    sampler must match token for token."""
+    V = cfg.vocab_size
+    row = logits[:V]
+    greedy = jnp.argmax(row).astype(jnp.int32)
+    rowf = row.astype(jnp.float32)
+    t = jnp.maximum(temp, 1e-6).astype(jnp.float32)
+    scaled = rowf / t
+    probs = jax.nn.softmax(scaled)
+    order = jnp.argsort(-probs)
+    sp = probs[order]
+    keep_sorted = (jnp.cumsum(sp) - sp) < jnp.maximum(top_p, 1e-6)
+    keep = jnp.zeros((row.shape[0],), bool).at[order].set(keep_sorted)
+    key = jax.random.fold_in(
+        jax.random.fold_in(jax.random.PRNGKey(seed), rid), pos)
+    g = jax.random.gumbel(key, (row.shape[0],), jnp.float32)
+    sampled = jnp.argmax(jnp.where(keep, scaled + g,
+                                   -jnp.inf)).astype(jnp.int32)
+    return jnp.where(temp <= 0.0, greedy, sampled)
+
+
+@partial(jax.jit, static_argnums=(0,))
+def _gather_scatter_sample_tokens(cfg, logits, temps, top_ps, seeds, rids,
+                                  pos):
+    return jax.vmap(
+        lambda lg, t, p, sd, rid, ps: _gather_scatter_sample_one(
+            cfg, lg, t, p, sd, rid, ps)
+    )(logits, temps, top_ps, seeds, rids, pos)
+
+
+SERVED_VOCAB = 50_280               # mamba2-370m's program vocabulary
+ROW_TEMPS = (0.0, 0.3, 0.8, 1.5)
+ROW_KINDS = ("ties", "peaked", "flat", "padded")
+
+
+def _rows(kind, V, rng):
+    """Eight logits rows, two per temperature in ``ROW_TEMPS``:
+    ``ties`` bf16 logits rounded to halves (a dozen distinct values, so
+    the sort's tie order decides the nucleus), ``peaked`` one token far
+    above the rest, ``flat`` near-uniform float32, ``padded`` bf16 rows
+    wider than the vocabulary whose pad columns would win if read."""
+    B = 2 * len(ROW_TEMPS)
+    if kind == "ties":
+        return jnp.asarray(np.round(rng.normal(size=(B, V)) * 2) / 2,
+                           jnp.bfloat16)
+    if kind == "peaked":
+        x = rng.normal(size=(B, V))
+        x[np.arange(B), rng.integers(0, V, size=B)] += 12.0
+        return jnp.asarray(x, jnp.bfloat16)
+    if kind == "flat":
+        return jnp.asarray(rng.normal(size=(B, V)) * 1e-3, jnp.float32)
+    assert kind == "padded"
+    pad = 128 - V % 128 + 128
+    x = np.concatenate([rng.normal(size=(B, V)),
+                        np.full((B, pad), 50.0)], axis=1)
+    return jnp.asarray(x, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("top_p", [1e-9, 0.5, 0.95, 1.0])
+@pytest.mark.parametrize("kind", ROW_KINDS)
+@pytest.mark.parametrize("vocab", ["smoke", SERVED_VOCAB])
+def test_two_sort_nucleus_matches_gather_scatter(vocab, kind, top_p):
+    """The sampler's nucleus mask, taken through a stable sort of
+    ``(-probs, iota)`` and a sort back by ``order``, selects exactly the
+    tokens the argsort/gather/scatter sampler selected: on tied bf16 rows,
+    peaked and near-uniform rows and padded rows, at every temperature
+    (greedy included) and top-p from a collapsed nucleus to the whole
+    vocabulary."""
+    cfg = get_smoke_config("qwen3-1.7b")
+    if vocab != "smoke":
+        cfg = dataclasses.replace(cfg, vocab_size=vocab)
+    V = cfg.vocab_size
+    rng = np.random.default_rng([V, ROW_KINDS.index(kind),
+                                 int(top_p * 1e9)])
+    logits = _rows(kind, V, rng)
+    B = logits.shape[0]
+    args = (logits,
+            jnp.asarray(np.repeat(ROW_TEMPS, 2), jnp.float32),
+            jnp.full((B,), top_p, jnp.float32),
+            jnp.asarray(rng.integers(0, 2**31 - 1, size=B), jnp.int32),
+            jnp.asarray(rng.integers(0, 10_000, size=B), jnp.int32),
+            jnp.asarray(rng.integers(0, 4096, size=B), jnp.int32))
+    got = np.asarray(fs.sample_tokens(cfg, *args))
+    want = np.asarray(_gather_scatter_sample_tokens(cfg, *args))
+    np.testing.assert_array_equal(got, want)
+    assert (got < V).all()
+
+
+def test_sampler_lowers_without_gather_or_scatter():
+    """Structural guard: the batched sampler at the served shape, (8,
+    50,280), lowers to StableHLO with no gather and no scatter, the
+    permutation traffic that took over half the TPU's decode step. The
+    gather/scatter reference lowers with both, so the probe can see them."""
+    cfg = dataclasses.replace(get_smoke_config("qwen3-1.7b"),
+                              vocab_size=SERVED_VOCAB)
+    B = 8
+    shapes = (jax.ShapeDtypeStruct((B, SERVED_VOCAB), jnp.bfloat16),
+              jax.ShapeDtypeStruct((B,), jnp.float32),
+              jax.ShapeDtypeStruct((B,), jnp.float32),
+              *(jax.ShapeDtypeStruct((B,), jnp.int32),) * 3)
+    text = fs.sample_tokens.lower(cfg, *shapes).as_text()
+    assert "stablehlo.sort" in text
+    assert "gather" not in text and "scatter" not in text
+    old = _gather_scatter_sample_tokens.lower(cfg, *shapes).as_text()
+    assert "stablehlo.gather" in old and "stablehlo.scatter" in old
 
 
 # ------------------------------------------------------------- simulator
