@@ -7,7 +7,9 @@ A cell (``BENCHMARK.json``'s ``workloads``) is a configuration
 (``configs/<name>.json``) under a traffic mix (``traffic/<name>.json``),
 served by the cluster that ``cells/<cell>.json`` lays out. The harness
 reads those files by name; a per-layer metric is read by
-``metrics/<metric name>.py``. Nothing here is specific to one cell.
+``metrics/<metric name>.py``, and what it knows of the configuration's
+program family by ``families/<family>.py``. Nothing here is specific to
+one cell or one family.
 
 One run:
 
@@ -51,6 +53,7 @@ for p in (str(HERE), str(ROOT / "src")):
 
 import numpy as np  # noqa: E402
 
+import families  # noqa: E402
 import traffic  # noqa: E402
 
 NO_CHIP = 3
@@ -282,14 +285,12 @@ def program_config(cell: Cell, override=None):
     cfg = override if override is not None else get_config(cell.config["program"])
     cfg = cfg.replace(attn_impl=c["attn_impl"])
     if override is None:
-        s = cfg.ssm
-        want = {k: c[k] for k in ("n_layers", "d_model", "vocab_size", "dtype",
-                                  "d_state", "d_conv", "expand",
-                                  "ssm_head_dim", "n_groups")}
-        got = {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
-               "vocab_size": cfg.vocab_size, "dtype": cfg.dtype,
-               "d_state": s.d_state, "d_conv": s.d_conv, "expand": s.expand,
-               "ssm_head_dim": s.head_dim, "n_groups": s.n_groups}
+        got = families.load(c["family"]).widths(cfg)
+        lacking = sorted(set(got) - set(c))
+        if lacking:
+            raise SystemExit(f"the run block of {cell.spec['config']!r} lacks "
+                             f"{lacking}, which its family compares")
+        want = {k: c[k] for k in got}
         if got != want:
             raise SystemExit(f"program config {got} is not the cell's {want}")
     return cfg
@@ -427,7 +428,6 @@ def warm_up(cluster, cell: Cell, prompt_lens, clog: "CompileLog"):
     the decode-only step; the step's token-array concatenations; and a
     decode-state transfer at each prompt length's bucket."""
     import jax.numpy as jnp
-    from repro.engine.instance import state_checksum
     cc = cell.cell
     a, b = cluster.instances[0], cluster.instances[1]
     n_slots = cc["n_slots"]
@@ -446,20 +446,39 @@ def warm_up(cluster, cell: Cell, prompt_lens, clog: "CompileLog"):
     for parts in sorted(concats):
         np.asarray(jnp.concatenate([jnp.zeros((n,), jnp.int32) for n in parts]))
     lens = sorted({min(-(-n // 32) * 32, cc["capacity"]) for n in prompt_lens})
-    if a.kv.prefix_reuse != "block":
-        lens = lens[:1]           # constant-size state: one transfer shape
+    return len(programs) + 1, len(concats), warm_transfers(a, b, lens)
+
+
+def warm_transfers(src, dst, lens: List[int]) -> int:
+    """Move a fake slot from ``src`` to ``dst`` through the instances' own
+    export and import at each length in ``lens``, or at the first alone
+    where the exported state has the same size at the first and the last
+    length: a state that does not grow with the context has one transfer
+    shape. Returns the number of shapes moved."""
+    from repro.engine.instance import state_checksum
     fake = -1
+
+    def export(n):
+        src.alloc_slot(fake)
+        src.kv.len_of[fake] = n
+        src.last_token[fake] = 1
+        src.generated[fake] = [1]
+        out = src.export_state(fake)
+        src.drop(fake)
+        return out
+
+    def nbytes(exported):
+        return sum(p.nbytes for p in exported[0])
+
+    ends = {lens[0]: export(lens[0]), lens[-1]: export(lens[-1])}
+    if nbytes(ends[lens[0]]) == nbytes(ends[lens[-1]]):
+        lens = lens[:1]
     for n in lens:
-        a.alloc_slot(fake)
-        a.kv.len_of[fake] = n
-        a.last_token[fake] = 1
-        a.generated[fake] = [1]
-        payload, L, last, gen = a.export_state(fake)
-        b.import_state(fake, payload, L, last, gen,
-                       checksum=state_checksum(payload))
-        a.drop(fake)
-        b.drop(fake)
-    return len(programs) + 1, len(concats), len(lens)
+        payload, L, last, gen = ends.pop(n, None) or export(n)
+        dst.import_state(fake, payload, L, last, gen,
+                         checksum=state_checksum(payload))
+        dst.drop(fake)
+    return len(lens)
 
 
 # ------------------------------------------------------------------- window
